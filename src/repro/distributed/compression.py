@@ -16,7 +16,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -58,7 +57,7 @@ def make_compressed_grad_fn(loss_fn, mesh):
     where the pod-axis reduction is int8-compressed with error feedback.
 
     The pod axis is manually mapped; everything else stays under the SPMD
-    partitioner (shard_map ``auto`` mode).
+    partitioner (only 'pod' is in the shard_map's ``axis_names``).
     """
     def local_grads(params, batch):
         # batch is the pod-local slice; loss mean is pod-local
@@ -66,12 +65,11 @@ def make_compressed_grad_fn(loss_fn, mesh):
         return loss, grads
 
     # Only the pod axis is manually mapped (we own what crosses pods);
-    # 'data'/'model' stay under the automatic SPMD partitioner via ``auto``.
-    @partial(shard_map, mesh=mesh,
+    # 'data'/'model' stay under the automatic SPMD partitioner.
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(), P("pod"), P()),
              out_specs=(P(), P(), P()),
-             check_rep=False,
-             auto=frozenset(a for a in mesh.axis_names if a != "pod"))
+             axis_names=frozenset({"pod"}), check_vma=False)
     def fn(params, batch, error_state):
         loss, grads = local_grads(params, batch)
         grads, new_err = compress_allreduce_pod(grads, error_state)

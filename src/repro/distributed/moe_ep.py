@@ -23,7 +23,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import MoEConfig
@@ -159,11 +158,11 @@ def moe_ffn_ep(x3d, params, cfg: MoEConfig, mesh):
                    "w_up": P(None, None, "model"), "w_down": P(None, "model", None)}
 
     x_spec = P(daxes, None, None) if daxes else P(None, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, w_specs),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = fn(x3d, params)
     return out, jnp.mean(aux)

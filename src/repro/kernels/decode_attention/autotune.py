@@ -51,24 +51,35 @@ def backend() -> str:
     return jax.default_backend()
 
 
+def _defaults(be: str | None) -> dict:
+    """The table row of backend ``be`` (default: the running backend).  A
+    backend missing from the table is an error: another backend's tiles are
+    no default for it."""
+    be = be or backend()
+    if be not in DEFAULTS:
+        raise ValueError(f"no autotune defaults for backend {be!r}; "
+                         f"known: {sorted(DEFAULTS)}")
+    return DEFAULTS[be]
+
+
 def default_page_size(be: str | None = None) -> int:
-    return DEFAULTS.get(be or backend(), DEFAULTS["cpu"])["page_size"]
+    return _defaults(be)["page_size"]
 
 
 def default_block_k(be: str | None = None) -> int:
-    return DEFAULTS.get(be or backend(), DEFAULTS["cpu"])["block_k"]
+    return _defaults(be)["block_k"]
 
 
 def default_chunk_size(be: str | None = None) -> int:
-    return DEFAULTS.get(be or backend(), DEFAULTS["cpu"])["chunk_size"]
+    return _defaults(be)["chunk_size"]
 
 
 def default_draft_len(be: str | None = None) -> int:
-    return DEFAULTS.get(be or backend(), DEFAULTS["cpu"])["draft_len"]
+    return _defaults(be)["draft_len"]
 
 
 def default_lmhead_block_v(be: str | None = None) -> int:
-    return DEFAULTS.get(be or backend(), DEFAULTS["cpu"])["lmhead_block_v"]
+    return _defaults(be)["lmhead_block_v"]
 
 
 def _time_jitted(fn, *args, reps: int = 10) -> float:

@@ -41,10 +41,11 @@ def _fa_kernel(w_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     # block-level skip: blocks entirely above the causal diagonal or entirely
     # outside the sliding window contribute nothing
-    live = jnp.bool_(True)
+    # window <= 0 means unlimited: an effective width no position reaches
+    win_eff = jnp.where(window > 0, window, jnp.int32(1 << 30))
+    live = k_start + block_k - 1 > q_start - win_eff
     if causal:
         live &= k_start <= q_start + block_q - 1
-    live &= jnp.where(window > 0, k_start + block_k - 1 > q_start - window, True)
 
     @pl.when(live)
     def _compute():
@@ -54,10 +55,11 @@ def _fa_kernel(w_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         s = q @ k.T                                           # (bq, bk)
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = jnp.ones_like(s, dtype=bool)
+        # boolean masks combine with & only: the TPU compiler cannot
+        # legalize a select between two boolean vectors
+        mask = k_pos > q_pos - win_eff
         if causal:
             mask &= k_pos <= q_pos
-        mask &= jnp.where(window > 0, k_pos > q_pos - window, True)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[...]

@@ -13,25 +13,26 @@ VMEM (chunk<=256, state n<=128, head dim p<=64 => < 1 MB).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _ssd_kernel(x_ref, acs_ref, b_ref, c_ref, o_ref):
-    x = x_ref[0, :, 0].astype(jnp.float32)        # (q, p)
-    acs = acs_ref[0, :, 0].astype(jnp.float32)    # (q,)
-    B = b_ref[0, :, 0].astype(jnp.float32)        # (q, n)
-    C = c_ref[0, :, 0].astype(jnp.float32)        # (q, n)
+def _ssd_kernel(x_ref, acol_ref, arow_ref, b_ref, c_ref, o_ref):
+    x = x_ref[0, 0].astype(jnp.float32)           # (q, p)
+    B = b_ref[0, 0].astype(jnp.float32)           # (q, n)
+    C = c_ref[0, 0].astype(jnp.float32)           # (q, n)
     q = x.shape[0]
-    scores = C @ B.T                              # (q, q)
+    scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)  # (q, q)
     t = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     u = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    L = jnp.where(t >= u, jnp.exp(acs[:, None] - acs[None, :]), 0.0)
-    y = (scores * L) @ x                          # (q, p)
-    o_ref[0, :, 0] = y.astype(o_ref.dtype)
+    # acs arrives as a column (q, 1) and a row (1, q): the kernel never
+    # transposes a vector
+    decay = jnp.exp(acol_ref[0, 0] - arow_ref[0, 0])
+    L = jnp.where(t >= u, decay, 0.0)
+    y = jnp.dot(scores * L, x, preferred_element_type=jnp.float32)   # (q, p)
+    o_ref[0, 0] = y.astype(o_ref.dtype)
 
 
 def ssd_intra_fwd(xb, acs, Bh, Ch, *, interpret: bool = False):
@@ -42,20 +43,25 @@ def ssd_intra_fwd(xb, acs, Bh, Ch, *, interpret: bool = False):
     Bh:  (bc, q, h, n) fp32
     Ch:  (bc, q, h, n) fp32
     Returns y_intra: (bc, q, h, p) fp32.
+
+    The kernel runs head-major, (bc, h, q, *): every block is then a whole
+    (q, *) tile, as the TPU block rule asks.
     """
     bc, q, h, p = xb.shape
     n = Bh.shape[-1]
-    grid = (bc, h)
-    return pl.pallas_call(
-        functools.partial(_ssd_kernel),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, q, 1, p), lambda b, hh: (b, 0, hh, 0)),
-            pl.BlockSpec((1, q, 1), lambda b, hh: (b, 0, hh)),
-            pl.BlockSpec((1, q, 1, n), lambda b, hh: (b, 0, hh, 0)),
-            pl.BlockSpec((1, q, 1, n), lambda b, hh: (b, 0, hh, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, q, 1, p), lambda b, hh: (b, 0, hh, 0)),
-        out_shape=jax.ShapeDtypeStruct((bc, q, h, p), jnp.float32),
+    heads_first = lambda a: jnp.moveaxis(a, 2, 1)
+    acs_hq = jnp.moveaxis(acs.astype(jnp.float32), 2, 1)     # (bc, h, q)
+
+    def spec(rows, cols):
+        return pl.BlockSpec((1, 1, rows, cols), lambda b, hh: (b, hh, 0, 0))
+
+    y = pl.pallas_call(
+        _ssd_kernel,
+        grid=(bc, h),
+        in_specs=[spec(q, p), spec(q, 1), spec(1, q), spec(q, n), spec(q, n)],
+        out_specs=spec(q, p),
+        out_shape=jax.ShapeDtypeStruct((bc, h, q, p), jnp.float32),
         interpret=interpret,
-    )(xb, acs, Bh, Ch)
+    )(heads_first(xb), acs_hq[..., None], acs_hq[:, :, None, :],
+      heads_first(Bh), heads_first(Ch))
+    return jnp.moveaxis(y, 1, 2)

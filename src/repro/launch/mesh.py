@@ -1,24 +1,25 @@
 """Production mesh definitions.
 
 Defined as FUNCTIONS so importing this module never touches jax device state
-(the dry-run sets XLA_FLAGS before any jax initialization).
+(the dry-run sets XLA_FLAGS before any jax initialization).  Every axis is
+``AxisType.Auto``: the code leaves sharding propagation to the compiler.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
     """Arbitrary mesh (elastic serving re-meshes at varying DP degrees)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh: Mesh) -> tuple[str, ...]:

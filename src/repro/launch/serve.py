@@ -167,7 +167,9 @@ def serve(args) -> int:
     from repro.data import request_stream
     from repro.models import build_model
     from repro.serving import Request, ServeConfig, ServingEngine
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     params = model.init_params(jax.random.key(args.seed))

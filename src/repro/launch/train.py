@@ -35,7 +35,9 @@ def train(args) -> int:
     from repro.models import build_model
     from repro.optim import AdamWConfig, adamw_init
     from repro.training import make_train_step
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     opt_cfg = AdamWConfig(lr=args.lr, total_steps=args.steps,
@@ -86,7 +88,10 @@ def train(args) -> int:
 
 
 def supervise(argv: list[str], max_restarts: int = 5) -> int:
-    """Heartbeat supervisor: restart the training subprocess on failure."""
+    """Heartbeat supervisor: restart the training subprocess on failure.
+
+    The parent never starts a JAX backend: a process that has touched the
+    accelerator holds it, and its children could then not train on it."""
     for attempt in range(max_restarts + 1):
         child = [sys.executable, "-m", "repro.launch.train"] + argv
         print(f"[supervisor] launch attempt {attempt}: {' '.join(child)}", flush=True)

@@ -41,7 +41,7 @@ TRACE_WRAPPERS = {
     "jax.jit", "jax.vmap", "jax.pmap", "jax.grad", "jax.value_and_grad",
     "jax.checkpoint", "jax.remat", "jax.eval_shape", "jax.linearize",
     "jax.vjp", "jax.jvp",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
 }
 
 #: control-flow primitives: which positional args are traced bodies

@@ -317,8 +317,9 @@ def _remat(fn, cfg: ModelConfig):
 
 
 # replint: traced -- jitted from the serving engine
-def forward(params, batch, cfg: ModelConfig, *, use_kernel: bool = False):
-    """Full-sequence forward -> (logits (B,S,V) f32, aux)."""
+def forward(params, batch, cfg: ModelConfig):
+    """Full-sequence forward -> (logits (B,S,V) f32, aux).  Always the jnp
+    path: training differentiates it."""
     x = _embed_in(params, batch, cfg)
     B, S, _ = x.shape
     cos, sin = rope_tables(jnp.arange(S), cfg.resolved_head_dim, cfg.rope_theta)
@@ -326,7 +327,7 @@ def forward(params, batch, cfg: ModelConfig, *, use_kernel: bool = False):
 
     def body(x, layer):
         bp, w = layer
-        x, _, aux = block_forward(x, bp, w, cos, sin, cfg, use_kernel)
+        x, _, aux = block_forward(x, bp, w, cos, sin, cfg, use_kernel=False)
         return x, aux
 
     body = _remat(body, cfg)
@@ -336,8 +337,8 @@ def forward(params, batch, cfg: ModelConfig, *, use_kernel: bool = False):
 
 
 # replint: traced -- jitted from the serving engine
-def loss_fn(params, batch, cfg: ModelConfig, *, use_kernel: bool = False):
-    logits, aux = forward(params, batch, cfg, use_kernel=use_kernel)
+def loss_fn(params, batch, cfg: ModelConfig):
+    logits, aux = forward(params, batch, cfg)
     tgt = batch["targets"]
     logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
     ll = jnp.take_along_axis(logp, tgt[:, 1:, None], axis=-1)[..., 0]

@@ -142,8 +142,8 @@ def forward(params, batch, cfg: ModelConfig, *, use_kernel: bool = False,
     return logits, jnp.float32(0.0)
 
 
-def loss_fn(params, batch, cfg: ModelConfig, *, use_kernel: bool = False):
-    logits, _ = forward(params, batch, cfg, use_kernel=use_kernel)
+def loss_fn(params, batch, cfg: ModelConfig):
+    logits, _ = forward(params, batch, cfg)
     tgt = batch["targets"]
     logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
     ll = jnp.take_along_axis(logp, tgt[:, 1:, None], axis=-1)[..., 0]

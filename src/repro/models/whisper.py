@@ -82,7 +82,7 @@ def _dec_cross_kv(bp, enc_out, cfg: ModelConfig):
     return xk, xv
 
 
-def forward(params, batch, cfg: ModelConfig, *, use_kernel: bool = False):
+def forward(params, batch, cfg: ModelConfig):
     """Teacher-forced training forward: batch = {enc_embeds, tokens}."""
     enc_out = encode(params, batch["enc_embeds"], cfg)
     x = params["embed"][batch["tokens"]]
@@ -108,7 +108,7 @@ def forward(params, batch, cfg: ModelConfig, *, use_kernel: bool = False):
     return _lm_head(params, x, cfg), jnp.float32(0.0)
 
 
-def loss_fn(params, batch, cfg: ModelConfig, *, use_kernel: bool = False):
+def loss_fn(params, batch, cfg: ModelConfig):
     logits, _ = forward(params, batch, cfg)
     tgt = batch["targets"]
     logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)

@@ -59,6 +59,13 @@ from repro.serving.kvcache import TRASH_PAGE, PagedKVCache
 from repro.serving.speculate import make_proposer, prefix_len
 
 
+def _device_of(params):
+    """The one device every array leaf of ``params`` lives on, else None."""
+    devs = {d for leaf in jax.tree.leaves(params)
+            if isinstance(leaf, jax.Array) for d in leaf.devices()}
+    return devs.pop() if len(devs) == 1 else None
+
+
 def _bucket(n: int) -> int:
     """Power-of-two length bucket, floor 16."""
     return 1 << max(int(np.ceil(np.log2(max(n, 1)))), 4)
@@ -128,12 +135,16 @@ class ServingEngine:
     ``run_until_drained`` runs at the full ``cfg.decode_steps`` sync cadence.
     This mirrors production continuous batching while staying simple enough
     to run under interpret-mode tests.
+
+    The KV pool is placed on the device that holds ``params``, so an engine
+    whose params sit on one chip runs entirely on that chip.
     """
 
     def __init__(self, model: Model, params, cfg: ServeConfig):
         self.model = model
         self.params = params
         self.cfg = cfg
+        self.device = _device_of(params)
         self.queue: list[Request] = []
         self.active: dict[int, Request] = {}       # slot -> request
         # dynamic cap on concurrently active slots (<= cfg.max_batch): the unit
@@ -178,7 +189,7 @@ class ServingEngine:
             page_size = cfg.page_size or autotune.default_page_size()
             self.kv = PagedKVCache(model.init_cache, max_batch=cfg.max_batch,
                                    max_len=cfg.max_len, page_size=page_size,
-                                   num_pages=cfg.num_pages)
+                                   num_pages=cfg.num_pages, device=self.device)
             self._prefill_jit = jax.jit(self._paged_prefill_fn)
             self._decode_jit = jax.jit(self._paged_decode_fn)
         else:

@@ -8,11 +8,12 @@ require scale-up to mean a NEW engine spawned from a checkpoint with a
 token.  Three parts (see DESIGN.md "The replica fleet"):
 
 * :class:`ReplicaPool` -- owns the lifecycle.  ``spawn`` loads the latest
-  checkpoint (`repro.checkpoint`), re-places params via
-  `repro.core.elastic.remesh.scale_replicas`, builds a
-  :class:`~repro.serving.ServingEngine`, and warms it with a probe decode
-  (compiling the mixed loop) -- the wall clock of all of that IS the
-  provisioning delay the plan prices (`CapacityPlan.calibrate_delay`).
+  checkpoint (`repro.checkpoint`), places the params on the device holding
+  the fewest live replicas (one replica per chip until every chip holds
+  one), builds a :class:`~repro.serving.ServingEngine` there, and warms it
+  with a probe decode (compiling the mixed loop) -- the wall clock of all
+  of that IS the provisioning delay the plan prices
+  (`CapacityPlan.calibrate_delay`).
   ``drain`` stops admitting and migrates every in-flight request by
   exporting its committed KV pages + positions
   (:meth:`~repro.serving.ServingEngine.export_request`) and re-admitting on
@@ -30,8 +31,8 @@ token.  Three parts (see DESIGN.md "The replica fleet"):
 * :class:`FleetExecutor` -- the convergence binding.  ``LaunchUnit`` /
   ``DrainUnit`` / ``ReplaceUnhealthy`` steps actuate the ReplicaPool; the
   CapacityPlan ledger is kept in sync as a side effect, so step timeouts,
-  stuck builds (a spawn that raises), and provisioning delays are MEASURED
-  at the engine level, not injected.
+  stuck builds (an injected :class:`SpawnFault`), and provisioning delays
+  are MEASURED at the engine level.
 
 :class:`FleetBackend` drives it all as a
 :class:`~repro.core.scaling.backend.ScalableBackend` (unit = replica) over
@@ -47,7 +48,6 @@ import jax
 import numpy as np
 
 from repro.checkpoint import load_checkpoint
-from repro.core.elastic.remesh import scale_replicas
 from repro.core.scaling import (
     ControllerConfig,
     RunReport,
@@ -64,6 +64,12 @@ FLEET_POOL = "replica"
 
 #: SignalBus channels a fleet backend records every virtual second
 FLEET_CHANNELS = ("output_score", "fleet_occupancy", "fleet_queue_depth")
+
+
+class SpawnFault(RuntimeError):
+    """A spawn failed on purpose (the pool's ``spawn_fault`` hook).  The
+    executor books exactly this as a measured stuck build; any other error --
+    a kernel the chip refuses, a replica that does not fit -- propagates."""
 
 
 class Replica:
@@ -110,16 +116,17 @@ class ReplicaPool:
 
     ``ckpt`` is either a :class:`~repro.checkpoint.CheckpointManager`
     (``latest()`` picks the newest complete checkpoint) or a direct ``.npz``
-    path.  ``spawn_fault`` is a test hook: a callable returning True makes
-    the next spawn raise -- the executor books it as a measured stuck build.
+    path.  Replicas are placed on ``jax.devices()``, least-loaded first.
+    ``spawn_fault`` is a test hook: a callable returning True makes the next
+    spawn raise :class:`SpawnFault` -- the executor books it as a measured
+    stuck build.
     """
 
     def __init__(self, model, ckpt, serve_cfg: ServeConfig, *,
-                 model_parallel: int = 1, spawn_fault=None):
+                 spawn_fault=None):
         self.model = model
         self.ckpt = ckpt
         self.serve_cfg = serve_cfg
-        self.model_parallel = model_parallel
         self.spawn_fault = spawn_fault
         self.serving: list[Replica] = []
         self.provisioning: list[tuple[float, Replica]] = []  # (ready_at, r)
@@ -136,18 +143,25 @@ class ReplicaPool:
             return path
         return self.ckpt
 
+    def _least_loaded_device(self):
+        """The device holding the fewest live (serving or provisioning)
+        replicas, lowest id on ties."""
+        live = [r.eng.device for r in self.serving]
+        live += [r.eng.device for _, r in self.provisioning]
+        return min(jax.devices(), key=lambda d: (live.count(d), d.id))
+
     def spawn(self) -> tuple[Replica, float]:
-        """Bring up one replica: checkpoint load -> remesh -> engine build ->
-        probe decode (compiles the mixed loop so the replica serves warm).
-        Returns ``(replica, measured wall seconds)``; raises on failure --
-        the caller books that as a stuck build."""
+        """Bring up one replica: checkpoint load -> params onto the
+        least-loaded device -> engine build there (its KV pool and jitted
+        steps follow the params) -> probe decode (compiles the mixed loop so
+        the replica serves warm).  Returns ``(replica, measured wall
+        seconds)``; an injected fault raises :class:`SpawnFault`."""
         t0 = time.perf_counter()
         if self.spawn_fault is not None and self.spawn_fault():
-            raise RuntimeError("spawn failed (injected)")
+            raise SpawnFault("spawn failed (injected)")
         params, _ = load_checkpoint(self._ckpt_path(),
                                     self.model.abstract_params())
-        _, params = scale_replicas(params, devices=jax.devices(),
-                                   model_parallel=self.model_parallel)
+        params = jax.device_put(params, self._least_loaded_device())
         eng = ServingEngine(self.model, params, self.serve_cfg)
         rix = self._next_rix
         self._next_rix += 1
@@ -350,9 +364,9 @@ class FleetExecutor:
 
     ``launch`` spawns for real and calibrates the pool's provisioning delay
     from the measured wall time BEFORE booking the unit, so the plan's
-    landing clock equals the replica's readiness; a spawn that raises is
-    booked as a measured stuck build, which the converger's existing
-    timeout / cancel / backoff machinery then handles."""
+    landing clock equals the replica's readiness; a spawn that raises
+    :class:`SpawnFault` is booked as a measured stuck build, which the
+    converger's existing timeout / cancel / backoff machinery then handles."""
 
     def __init__(self, pool: ReplicaPool, plan, name: str = FLEET_POOL, *,
                  calibrate: bool = True):
@@ -371,7 +385,7 @@ class FleetExecutor:
         for _ in range(int(count)):
             try:
                 rep, dt = self.pool.spawn()
-            except RuntimeError:
+            except SpawnFault:
                 applied += self.plan.queue_stuck(pool, 1, now)
                 self._stuck += 1
                 continue
@@ -584,4 +598,4 @@ class FleetBackend:
 
 
 __all__ = ["FLEET_CHANNELS", "FLEET_POOL", "FleetBackend", "FleetExecutor",
-           "FleetRouter", "Replica", "ReplicaPool"]
+           "FleetRouter", "Replica", "ReplicaPool", "SpawnFault"]

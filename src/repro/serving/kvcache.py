@@ -216,11 +216,13 @@ class PagedKVCache:
     ``init_cache_fn(batch, max_len)`` is the model's cache constructor; its
     leaf layout (L, B, S, *rest) is reinterpreted as per-page (L, P, ps, *rest)
     pools, so the same class serves f32/bf16 and int8 (value + scale leaves)
-    caches without knowing the schema.
+    caches without knowing the schema.  ``device``: where the pools live
+    (None: JAX's default placement).
     """
 
     def __init__(self, init_cache_fn, *, max_batch: int, max_len: int,
-                 page_size: int = 16, num_pages: int | None = None):
+                 page_size: int = 16, num_pages: int | None = None,
+                 device=None):
         if page_size < 1 or page_size & (page_size - 1):
             # power of two: every pow2 prefill bucket >= page_size is then a
             # whole number of page chunks
@@ -236,7 +238,7 @@ class PagedKVCache:
         proto = jax.eval_shape(lambda: init_cache_fn(1, page_size))
         self.pages = jax.tree.map(
             lambda s: jnp.zeros((s.shape[0], self.num_pages) + s.shape[2:],
-                                s.dtype), proto)
+                                s.dtype, device=device), proto)
         self.block_table = np.zeros((max_batch, self.pages_per_slot), np.int32)
         self.held = np.zeros(max_batch, np.int32)         # pages owned per slot
         self.worst = np.zeros(max_batch, np.int32)        # reserved worst case

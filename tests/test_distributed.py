@@ -22,6 +22,7 @@ def _run(code: str) -> str:
 def test_sharded_train_step_matches_single_device():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import Mesh
         from repro.configs import get_smoke_config
         from repro.models import build_model
@@ -40,7 +41,7 @@ def test_sharded_train_step_matches_single_device():
         # single device reference
         p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         with mesh:
             p_sh, o_sh, b_sh = train_state_shardings(model, mesh,
                 jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), batch))
@@ -84,6 +85,7 @@ def test_elastic_remesh_preserves_values():
 def test_checkpoint_restore_resharded():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np, tempfile, os
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.checkpoint import save_checkpoint, restore_resharded
@@ -96,7 +98,7 @@ def test_checkpoint_restore_resharded():
         p = os.path.join(d, 'ck.npz')
         save_checkpoint(p, params, step=1)
         # restore onto a DIFFERENT mesh shape than the save-time layout
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         abstract = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
         sh = param_sharding(abstract, mesh)
         restored, meta = restore_resharded(p, params, sh)
@@ -111,19 +113,19 @@ def test_checkpoint_restore_resharded():
 def test_int8_pod_gradient_compression():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from functools import partial
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import (
             compress_allreduce_pod, init_error_state)
 
-        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
         grads = {'w': jnp.linspace(-1, 1, 64).reshape(8, 8)}
         err = init_error_state(grads)
 
-        @partial(shard_map, mesh=mesh, in_specs=(P(), P()),
-                 out_specs=(P(), P()), check_rep=False,
-                 auto=frozenset({'data', 'model'}))
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
+                 out_specs=(P(), P()), axis_names=frozenset({'pod'}),
+                 check_vma=False)
         def f(g, e):
             return compress_allreduce_pod(g, e)
 
@@ -143,6 +145,7 @@ def test_dryrun_cell_small_mesh():
     """A miniature dry-run cell: lower+compile on an in-test 8-device mesh."""
     out = _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from jax.sharding import Mesh
         from repro.configs import get_smoke_config
         from repro.models import build_model
@@ -151,7 +154,7 @@ def test_dryrun_cell_small_mesh():
 
         cfg = get_smoke_config('olmoe-1b-7b')     # MoE: exercises EP sharding
         model = build_model(cfg)
-        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
         with mesh:
             p_abs = model.abstract_params()
             specs = {'tokens': jax.ShapeDtypeStruct((8, 32), jnp.int32),

@@ -1,6 +1,11 @@
 """Replica fleet: single-replica behavioral equivalence (pinned), drain
 migration conservation, measured provisioning delay, SLA-aware routing, and
 convergence-plane healing of killed replicas (see repro.serving.fleet)."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 
@@ -14,6 +19,8 @@ from repro.serving.fleet import (
     FleetRouter,
     ReplicaPool,
 )
+
+ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 @pytest.fixture(scope="module")
 def fleet_env(tmp_path_factory):
@@ -318,6 +325,99 @@ def test_executor_books_stuck_spawn_and_cancels_it_first(fleet_env):
     # cancel the other: now the provisioning replica is discarded
     assert ex.cancel_pending(FLEET_POOL, 1, now=2.0) == 1
     assert not pool.provisioning and len(pool.retired) == 1
+
+
+def test_executor_lets_real_spawn_errors_propagate(fleet_env):
+    """Only an injected SpawnFault is a stuck build.  A real failure -- a
+    kernel the chip's compiler refuses, a replica that does not fit (JAX
+    raises both as RuntimeError subclasses) -- must surface, not be booked
+    as a stuck build while the run exits 0."""
+    cfg, pool = _make_pool(fleet_env, 0)
+
+    class CompileError(RuntimeError):
+        pass
+
+    def refuse():
+        raise CompileError("Mosaic failed to compile TPU kernel")
+
+    pool.spawn = refuse
+    plan = CapacityPlan((UnitPool(FLEET_POOL, provision_delay_s=5.0,
+                                  max_units=4),), starting_units=0)
+    ex = FleetExecutor(pool, plan)
+    with pytest.raises(CompileError):
+        ex.launch(FLEET_POOL, 1, now=0.0)
+    assert ex._stuck == 0
+
+
+def test_replicas_own_one_device_each_and_drain_across_devices():
+    """On a four-device host every replica's params, KV pool and steps sit
+    on its own device, and a mid-run drain moves committed KV to another
+    device with outputs bit-identical to a one-replica run (subprocess:
+    four forced host devices)."""
+    env = {**os.environ,
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": os.path.join(ROOT, "src")}
+    code = textwrap.dedent("""
+        import jax, numpy as np, os, tempfile
+        from repro.checkpoint import save_checkpoint
+        from repro.configs import get_smoke_config
+        from repro.models import build_model
+        from repro.serving import Request, ServeConfig
+        from repro.serving.fleet import FleetRouter, ReplicaPool
+
+        cfg = get_smoke_config('smollm-135m')
+        model = build_model(cfg)
+        ckpt = save_checkpoint(os.path.join(tempfile.mkdtemp(), 'c.npz'),
+                               model.init_params(jax.random.key(0)))
+        serve_cfg = ServeConfig(max_batch=4, max_len=128, decode_steps=4)
+
+        def run(n, drain):
+            pool = ReplicaPool(model, ckpt, serve_cfg)
+            for _ in range(n):
+                pool.serving.append(pool.spawn()[0])
+            rng = np.random.default_rng(3)
+            reqs = [Request(rid=i, prompt=rng.integers(
+                        0, cfg.vocab, 8 + 4 * i).astype(np.int32),
+                        max_new_tokens=24) for i in range(8)]
+            router = FleetRouter(pool)
+            for r in reqs:
+                router.submit(r)
+            moved = None
+            for t in range(200):
+                router.dispatch(float(t))
+                for rep in pool.serving:
+                    rep.step(float(t), decode_steps=2)
+                if drain and t == 0:
+                    victim = pool.serving[0]
+                    kv = {r.rid for s, r in victim.eng.active.items()
+                          if victim.eng.pos[s] > 0}
+                    pool.drain(victim)
+                    moved = (kv, victim.eng.device,
+                             {r.eng.device for r in pool.serving
+                              for q in r.eng.active.values() if q.rid in kv})
+                if not router.backlog and not any(
+                        r.eng.n_in_system for r in pool.serving):
+                    break
+            for rep in pool.serving + pool.retired:
+                rep.eng.kv.check_invariants()
+            return pool, {r.rid: r.output for r in reqs}, moved
+
+        _, ref, _ = run(1, False)
+        pool, out, (kv, left, landed) = run(4, True)
+        reps = pool.serving + pool.retired
+        assert len({r.eng.device for r in reps}) == 4
+        for r in reps:
+            held = {d for leaf in jax.tree.leaves((r.eng.params, r.eng.kv.pages))
+                    for d in leaf.devices()}
+            assert held == {r.eng.device}, (held, r.eng.device)
+        assert kv and landed and left not in landed, (kv, left, landed)
+        assert out == ref
+        print('PLACEMENT_OK')
+    """)
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    assert "PLACEMENT_OK" in p.stdout
 
 
 def test_chaos_drill_kill_under_load_is_observationally_equivalent(

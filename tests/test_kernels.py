@@ -240,3 +240,15 @@ def test_ssd_chunked_matches_naive_recurrence():
     y_naive = jnp.stack(ys, axis=1)
     np.testing.assert_allclose(np.asarray(y_chunked, np.float32),
                                np.asarray(y_naive, np.float32), atol=2e-3, rtol=2e-3)
+
+
+def test_autotune_defaults_refuse_an_unknown_backend():
+    """A backend missing from the table gets an error, not another
+    backend's tile sizes."""
+    from repro.kernels.decode_attention import autotune
+    assert autotune.default_page_size("tpu") == autotune.DEFAULTS["tpu"]["page_size"]
+    for fn in (autotune.default_page_size, autotune.default_block_k,
+               autotune.default_chunk_size, autotune.default_draft_len,
+               autotune.default_lmhead_block_v):
+        with pytest.raises(ValueError, match="no autotune defaults"):
+            fn("metal")

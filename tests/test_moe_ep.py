@@ -21,6 +21,7 @@ def _run(code: str) -> str:
 def test_ep_and_tp_modes_bit_identical():
     out = _run("""
         import os, jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.distributed import moe_ep
@@ -33,7 +34,7 @@ def test_ep_and_tp_modes_bit_identical():
             params = model.init_params(jax.random.key(0))
             toks = jax.random.randint(jax.random.key(1), (2, 32), 0, cfg.vocab)
             batch = {'tokens': toks}
-            mesh = jax.make_mesh(mesh_shape, ('data', 'model'))
+            mesh = make_mesh(mesh_shape, ('data', 'model'))
             moe_ep.set_ep_mesh(mesh)
             with mesh:
                 p_sh = param_sharding(model.abstract_params(), mesh)
@@ -53,6 +54,7 @@ def test_ep_and_tp_modes_bit_identical():
 def test_ep_loss_and_grads_close_to_unsharded():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_smoke_config
         from repro.models import build_model
         from repro.distributed import moe_ep
@@ -65,7 +67,7 @@ def test_ep_loss_and_grads_close_to_unsharded():
         batch = {'tokens': toks, 'targets': toks}
         moe_ep.set_ep_mesh(None)
         l0, _ = jax.jit(model.loss_fn)(params, batch)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         moe_ep.set_ep_mesh(mesh)
         with mesh:
             p_sh = param_sharding(model.abstract_params(), mesh)
