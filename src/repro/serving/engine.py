@@ -51,6 +51,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels.decode_attention import autotune
 from repro.kernels.sampling.ops import greedy_epilogue
@@ -182,9 +183,13 @@ class ServingEngine:
             self.span = 1
             self.proposer = None
             self._mixed_jit = None
-        # speculation / interleave stats (bench artifact)
+        # mixed-loop counters (speculation_stats), summed at each sync
         self._mixed_emitted = 0                    # tokens emitted by mixed loop
         self._mixed_live_iters = 0                 # live-row loop iterations
+        self._mixed_computed = 0                   # max_batch x span x iters
+        self._mixed_committed = 0                  # KV positions committed
+        self._kv_reserved_iters = 0                # reserved pages x iters
+        self._kv_committed_iters = 0               # committed pages x iters
         if self.paged:
             page_size = cfg.page_size or autotune.default_page_size()
             self.kv = PagedKVCache(model.init_cache, max_batch=cfg.max_batch,
@@ -452,14 +457,27 @@ class ServingEngine:
 
     @property
     def speculation_stats(self) -> dict[str, float]:
-        """Mixed-loop throughput counters: tokens emitted, live-row loop
-        iterations, and their ratio (tokens per row-step; > 1 means
-        speculation is beating one-token-per-step decode)."""
+        """Mixed-loop counters, summed over every sync since the engine
+        was built:
+
+        * ``emitted`` / ``live_iters``: tokens emitted and live-row loop
+          iterations, and their ratio ``tokens_per_row_step`` (> 1 means
+          speculation is beating one-token-per-step decode);
+        * ``computed_positions``: positions the loop computed
+          (max_batch x span per iteration) and ``committed_positions``:
+          KV positions it committed (prompt chunks plus accepted tokens);
+        * ``kv_reserved_page_iters`` / ``kv_committed_page_iters``: pages
+          reserved for the served slots, and pages their committed KV
+          fills at the sync, each times the loop iterations."""
         return {
             "emitted": float(self._mixed_emitted),
             "live_iters": float(self._mixed_live_iters),
             "tokens_per_row_step": (self._mixed_emitted
                                     / max(self._mixed_live_iters, 1)),
+            "computed_positions": float(self._mixed_computed),
+            "committed_positions": float(self._mixed_committed),
+            "kv_reserved_page_iters": float(self._kv_reserved_iters),
+            "kv_committed_page_iters": float(self._kv_committed_iters),
         }
 
     # -- slot lifecycle -----------------------------------------------------------
@@ -719,35 +737,55 @@ class ServingEngine:
         self._reset_slot(slot)
 
     def _apply_decode_outputs(self, rows, out_toks, lp_sum, n_emit, pos_out,
-                              rem_out, now: float) -> None:
-        """Fold one device-loop sync back into host bookkeeping.
+                              rem_out, iters, now: float,
+                              live_iters=None) -> int:
+        """Fold one device-loop sync back into host bookkeeping; returns the
+        loop iterations run.
 
         ``rows``: [(batch row, slot)] -- compacted index order for the paged
-        path, identity (slot == row) for the dense path."""
-        out_toks = np.asarray(out_toks)
-        lp_sum = np.asarray(lp_sum)
-        n_emit = np.asarray(n_emit)
-        pos_out = np.asarray(pos_out)
-        rem_out = np.asarray(rem_out)
-        finished = []
-        for i, s in rows:
-            # position/budget always advance (a mixed-loop row can commit
-            # prefill chunks without emitting a single token)
-            self.pos[s] = int(pos_out[i])
-            self.remaining[s] = int(rem_out[i])
-            ne = int(n_emit[i])
-            if ne == 0:
-                continue
-            req = self.active[s]
-            prev = len(req.output)
-            if prev == 0:
-                req.first_token_s = now
-            req.output.extend(int(t) for t in out_toks[i, :ne])
-            req.score = (req.score * prev + float(lp_sum[i])) / (prev + ne)
-            if rem_out[i] <= 0 or req.output[-1] == self.cfg.eos_token:
-                finished.append(s)
-        for s in finished:
-            self._finish(s, now)
+        path, identity (slot == row) for the dense path.  Every output comes
+        to the host in one fetch (``serve.sync``: the wait for the loop and
+        the copy), then the fold (``serve.fold``).  ``live_iters`` is the
+        mixed loop's: given, the fold adds to its counters."""
+        with TraceAnnotation("serve.sync"):
+            (out_toks, lp_sum, n_emit, pos_out, rem_out, iters,
+             live_iters) = jax.device_get((out_toks, lp_sum, n_emit, pos_out,
+                                           rem_out, iters, live_iters))
+        with TraceAnnotation("serve.fold"):
+            iters = int(iters)
+            committed = 0
+            finished = []
+            for i, s in rows:
+                # position/budget always advance (a mixed-loop row can commit
+                # prefill chunks without emitting a single token)
+                committed += int(pos_out[i]) - int(self.pos[s])
+                self.pos[s] = int(pos_out[i])
+                self.remaining[s] = int(rem_out[i])
+                ne = int(n_emit[i])
+                if ne == 0:
+                    continue
+                req = self.active[s]
+                prev = len(req.output)
+                if prev == 0:
+                    req.first_token_s = now
+                req.output.extend(int(t) for t in out_toks[i, :ne])
+                req.score = (req.score * prev + float(lp_sum[i])) / (prev + ne)
+                if rem_out[i] <= 0 or req.output[-1] == self.cfg.eos_token:
+                    finished.append(s)
+            if live_iters is not None:
+                # before _finish releases the finished rows' pages
+                slots = [s for _, s in rows]
+                ps = self.kv.page_size
+                self._mixed_emitted += int(n_emit.sum())
+                self._mixed_live_iters += int(live_iters)
+                self._mixed_computed += self.cfg.max_batch * self.span * iters
+                self._mixed_committed += committed
+                self._kv_reserved_iters += self.kv.n_reserved * iters
+                self._kv_committed_iters += iters * int(
+                    ((self.pos[slots] + ps - 1) // ps).sum())
+            for s in finished:
+                self._finish(s, now)
+        return iters
 
     def _decode_active_paged(self, now: float, k: int = 1) -> tuple[int, int]:
         """Up to ``k`` batched heterogeneous-position decode steps over the
@@ -757,29 +795,29 @@ class ServingEngine:
         n = len(slots)
         if n == 0:
             return 0, 0                  # guard: np.log2(0) and an empty jit
-        na = 1 << max(int(np.ceil(np.log2(n))), 0)
-        toks = np.zeros((na, 1), np.int32)
-        posv = np.zeros((na,), np.int32)
-        remv = np.zeros((na,), np.int32)
-        livev = np.zeros((na,), bool)
-        tblv = np.zeros((na, self.kv.pages_per_slot), np.int32)
-        for i, s in enumerate(slots):
-            # pre-allocate every page the next k on-device writes may touch
-            span = min(k, int(self.remaining[s]))
-            self.kv.ensure_writable_span(s, int(self.pos[s]), max(span, 1))
-            toks[i, 0] = self.active[s].output[-1]
-            posv[i] = self.pos[s]
-            remv[i] = self.remaining[s]
-            livev[i] = True
-            tblv[i] = self.kv.block_table[s]
-        self.kv.pages, out_toks, lp_sum, n_emit, pos_out, rem_out, iters = \
-            self._decode_jit(self.params, self.kv.pages, jnp.asarray(toks),
-                             jnp.asarray(posv), jnp.asarray(remv),
-                             jnp.asarray(livev), jnp.asarray(tblv),
-                             jnp.int32(k))
-        self._apply_decode_outputs(list(enumerate(slots)), out_toks, lp_sum,
-                                   n_emit, pos_out, rem_out, now)
-        return n, int(iters)
+        with TraceAnnotation("serve.pack"):
+            na = 1 << max(int(np.ceil(np.log2(n))), 0)
+            toks = np.zeros((na, 1), np.int32)
+            posv = np.zeros((na,), np.int32)
+            remv = np.zeros((na,), np.int32)
+            livev = np.zeros((na,), bool)
+            tblv = np.zeros((na, self.kv.pages_per_slot), np.int32)
+            for i, s in enumerate(slots):
+                # pre-allocate every page the next k on-device writes may touch
+                span = min(k, int(self.remaining[s]))
+                self.kv.ensure_writable_span(s, int(self.pos[s]), max(span, 1))
+                toks[i, 0] = self.active[s].output[-1]
+                posv[i] = self.pos[s]
+                remv[i] = self.remaining[s]
+                livev[i] = True
+                tblv[i] = self.kv.block_table[s]
+        with TraceAnnotation("serve.launch"):
+            self.kv.pages, *outs = self._decode_jit(
+                self.params, self.kv.pages, jnp.asarray(toks),
+                jnp.asarray(posv), jnp.asarray(remv), jnp.asarray(livev),
+                jnp.asarray(tblv), jnp.int32(k))
+        iters = self._apply_decode_outputs(list(enumerate(slots)), *outs, now)
+        return n, iters
 
     def _decode_active_mixed(self, now: float, k: int = 1) -> tuple[int, int]:
         """Up to ``k`` mixed chunked-prefill / speculative steps over the
@@ -792,47 +830,49 @@ class ServingEngine:
         n = len(slots)
         if n == 0:
             return 0, 0
-        na = self.cfg.max_batch
-        T = self.span
-        H = self.cfg.max_len + 1           # prompt + every emitted token
-        hist = np.zeros((na, H), np.int32)
-        ellv = np.zeros((na,), np.int32)
-        posv = np.zeros((na,), np.int32)
-        remv = np.zeros((na,), np.int32)
-        livev = np.zeros((na,), bool)
-        tblv = np.zeros((na, self.kv.pages_per_slot), np.int32)
-        for i, s in enumerate(slots):
-            req = self.active[s]
-            plen = len(req.prompt)
-            hist[i, :plen] = req.prompt
-            if req.output:
-                hist[i, plen:plen + len(req.output)] = req.output
-            ellv[i] = plen + len(req.output)
-            total = plen + req.max_new_tokens - 1
-            # pre-allocate every page the next k on-device spans may write;
-            # writes past ``total`` hit TRASH table entries harmlessly, so
-            # the span never outgrows the admission reservation
-            span = min(k * T, total - int(self.pos[s]))
-            self.kv.ensure_writable_span(s, int(self.pos[s]), max(span, 1))
-            posv[i] = self.pos[s]
-            remv[i] = self.remaining[s]
-            livev[i] = True
-            tblv[i] = self.kv.block_table[s]
-        (self.kv.pages, out_toks, lp_sum, n_emit, pos_out, rem_out, iters,
-         live_iters) = self._mixed_jit(
-            self.params, self.kv.pages, jnp.asarray(hist), jnp.asarray(ellv),
-            jnp.asarray(posv), jnp.asarray(remv), jnp.asarray(livev),
-            jnp.asarray(tblv), jnp.int32(k))
-        self._apply_decode_outputs(list(enumerate(slots)), out_toks, lp_sum,
-                                   n_emit, pos_out, rem_out, now)
-        self._mixed_emitted += int(np.asarray(n_emit).sum())
-        self._mixed_live_iters += int(live_iters)
+        with TraceAnnotation("serve.pack"):
+            na = self.cfg.max_batch
+            T = self.span
+            H = self.cfg.max_len + 1       # prompt + every emitted token
+            hist = np.zeros((na, H), np.int32)
+            ellv = np.zeros((na,), np.int32)
+            posv = np.zeros((na,), np.int32)
+            remv = np.zeros((na,), np.int32)
+            livev = np.zeros((na,), bool)
+            tblv = np.zeros((na, self.kv.pages_per_slot), np.int32)
+            for i, s in enumerate(slots):
+                req = self.active[s]
+                plen = len(req.prompt)
+                hist[i, :plen] = req.prompt
+                if req.output:
+                    hist[i, plen:plen + len(req.output)] = req.output
+                ellv[i] = plen + len(req.output)
+                total = plen + req.max_new_tokens - 1
+                # pre-allocate every page the next k on-device spans may
+                # write; writes past ``total`` hit TRASH table entries
+                # harmlessly, so the span never outgrows the reservation
+                span = min(k * T, total - int(self.pos[s]))
+                self.kv.ensure_writable_span(s, int(self.pos[s]), max(span, 1))
+                posv[i] = self.pos[s]
+                remv[i] = self.remaining[s]
+                livev[i] = True
+                tblv[i] = self.kv.block_table[s]
+        with TraceAnnotation("serve.launch"):
+            (self.kv.pages, out_toks, lp_sum, n_emit, pos_out, rem_out, iters,
+             live_iters) = self._mixed_jit(
+                self.params, self.kv.pages, jnp.asarray(hist),
+                jnp.asarray(ellv), jnp.asarray(posv), jnp.asarray(remv),
+                jnp.asarray(livev), jnp.asarray(tblv), jnp.int32(k))
+        iters = self._apply_decode_outputs(
+            list(enumerate(slots)), out_toks, lp_sum, n_emit, pos_out, rem_out,
+            iters, now, live_iters=live_iters)
         # KV rollback: hand back pages that only ever held rejected
         # speculative writes (the next span re-appends them if accepted)
-        for s in slots:
-            if s in self.active:
-                self.kv.shrink_to(s, max(int(self.pos[s]), 1))
-        return n, int(iters)
+        with TraceAnnotation("serve.rollback"):
+            for s in slots:
+                if s in self.active:
+                    self.kv.shrink_to(s, max(int(self.pos[s]), 1))
+        return n, iters
 
     def _decode_all_dense(self, now: float, k: int = 1) -> tuple[int, int]:
         """Legacy fallback (no paged cache): batch-decode every slot of the
@@ -841,25 +881,32 @@ class ServingEngine:
         slots = sorted(self.active)
         if not slots:
             return 0, 0                  # guard: empty active set
-        toks = np.zeros((self.cfg.max_batch, 1), np.int32)
-        livev = np.zeros((self.cfg.max_batch,), bool)
-        for slot, req in self.active.items():
-            toks[slot, 0] = req.output[-1]
-            livev[slot] = True
-        self.cache, out_toks, lp_sum, n_emit, pos_out, rem_out, iters = \
-            self._decode_jit(self.params, self.cache, jnp.asarray(toks),
-                             jnp.asarray(self.pos), jnp.asarray(self.remaining),
-                             jnp.asarray(livev), jnp.int32(k))
-        self._apply_decode_outputs([(s, s) for s in slots], out_toks, lp_sum,
-                                   n_emit, pos_out, rem_out, now)
-        return len(slots), int(iters)
+        with TraceAnnotation("serve.pack"):
+            toks = np.zeros((self.cfg.max_batch, 1), np.int32)
+            livev = np.zeros((self.cfg.max_batch,), bool)
+            for slot, req in self.active.items():
+                toks[slot, 0] = req.output[-1]
+                livev[slot] = True
+        with TraceAnnotation("serve.launch"):
+            self.cache, *outs = self._decode_jit(
+                self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(self.pos), jnp.asarray(self.remaining),
+                jnp.asarray(livev), jnp.int32(k))
+        iters = self._apply_decode_outputs([(s, s) for s in slots], *outs, now)
+        return len(slots), iters
 
     def step(self, now: float | None = None, *,
              decode_steps: int | None = None) -> int:
         """One engine step: refill + one batched device loop over the active
         slots (``decode_steps`` tokens per slot, default 1).  Returns the
         number of slots that served work this step (decodes plus fill-time
-        completions)."""
+        completions).
+
+        The step is the ``serve.step`` span of a profiler trace (arguments
+        ``k`` and ``rows``, the slots the loop serves), holding
+        ``serve.fill`` and the loop's ``serve.pack``, ``serve.launch``,
+        ``serve.sync``, ``serve.fold`` and ``serve.rollback``; they are
+        recorded only while a profiler session runs."""
         now = time.monotonic() if now is None else now
         k = max(int(decode_steps or 1), 1)
         if k > self.decode_steps:
@@ -870,19 +917,22 @@ class ServingEngine:
                 f"decode_steps={k} > ServeConfig.decode_steps="
                 f"{self.decode_steps}; raise the config to burst this far")
         self._clock += 1
-        fill_done = self._fill_slots(now)
-        if not self.active:
-            if fill_done:
-                self.step_count += 1
-            return fill_done
-        if self.chunked:
-            served, iters = self._decode_active_mixed(now, k)
-        elif self.paged:
-            served, iters = self._decode_active_paged(now, k)
-        else:
-            served, iters = self._decode_all_dense(now, k)
-        self.step_count += max(iters, 1)
-        return served + fill_done
+        with TraceAnnotation("serve.step", k=k) as span:
+            with TraceAnnotation("serve.fill"):
+                fill_done = self._fill_slots(now)
+            span.set_metadata(rows=len(self.active))
+            if not self.active:
+                if fill_done:
+                    self.step_count += 1
+                return fill_done
+            if self.chunked:
+                served, iters = self._decode_active_mixed(now, k)
+            elif self.paged:
+                served, iters = self._decode_active_paged(now, k)
+            else:
+                served, iters = self._decode_all_dense(now, k)
+            self.step_count += max(iters, 1)
+            return served + fill_done
 
     def run_until_drained(self, max_steps: int = 10_000) -> None:
         """Drain queue + active set at the full device-resident sync cadence

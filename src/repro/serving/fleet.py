@@ -46,6 +46,7 @@ import time
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import load_checkpoint
 from repro.core.scaling import (
@@ -302,60 +303,62 @@ class FleetRouter:
         return req.arrival_s + self.sla.deadline_s(f"p{pb}d{db}")
 
     def dispatch(self, now: float) -> int:
-        """One admission pass; returns requests placed on a replica."""
+        """One admission pass; returns requests placed on a replica.  The
+        pass is the ``serve.dispatch`` span of a profiler trace."""
         del now
-        pool = self.pool
-        placed = 0
-        folded = False
-        backlog, pool.migrated = pool.migrated, []
-        for m in backlog:
-            if _restartable(m):            # no progress: back through the
-                self.queue.append(m.req)   # queue at the original deadline
-                folded = True
-            else:                          # re-admission keeps progress
-                placed += bool(pool.place_migrated(m))
-        if self.sla is not None and len(self.queue) > 1:
-            self.queue.sort(key=self._deadline)   # stable: FIFO within ties
-        elif folded and len(self.queue) > 1:
-            # no SLA classes: restore global arrival order (stable, so
-            # same-arrival submits keep their relative order)
-            self.queue.sort(key=lambda r: r.arrival_s)
-        # per-replica pages/slots promised in THIS pass (reservations only
-        # execute inside the engine's next step)
-        planned: dict[int, int] = {}
-        taken: dict[int, int] = {}
-        while self.queue:
-            req = self.queue[0]
-            if req.max_new_tokens <= 0:    # completes at fill time, no slot
-                target = next((r for r in self.pool.serving
-                               if not r.draining and r.healthy), None)
+        with TraceAnnotation("serve.dispatch"):
+            pool = self.pool
+            placed = 0
+            folded = False
+            backlog, pool.migrated = pool.migrated, []
+            for m in backlog:
+                if _restartable(m):            # no progress: back through the
+                    self.queue.append(m.req)   # queue at the original deadline
+                    folded = True
+                else:                          # re-admission keeps progress
+                    placed += bool(pool.place_migrated(m))
+            if self.sla is not None and len(self.queue) > 1:
+                self.queue.sort(key=self._deadline)  # stable: FIFO in ties
+            elif folded and len(self.queue) > 1:
+                # no SLA classes: restore global arrival order (stable, so
+                # same-arrival submits keep their relative order)
+                self.queue.sort(key=lambda r: r.arrival_s)
+            # per-replica pages/slots promised in THIS pass (reservations only
+            # execute inside the engine's next step)
+            planned: dict[int, int] = {}
+            taken: dict[int, int] = {}
+            while self.queue:
+                req = self.queue[0]
+                if req.max_new_tokens <= 0:  # completes at fill, no slot
+                    target = next((r for r in self.pool.serving
+                                   if not r.draining and r.healthy), None)
+                    if target is None:
+                        break
+                    self.queue.pop(0)
+                    target.eng.submit(req)
+                    placed += 1
+                    continue
+                total = len(req.prompt) + req.max_new_tokens - 1
+                target = None
+                for r in sorted(self.pool.serving,
+                                key=lambda r: (-(r.free_slots
+                                                 - taken.get(r.rix, 0)), r.rix)):
+                    if r.draining or not r.healthy:
+                        continue
+                    if (r.free_slots - taken.get(r.rix, 0) > 0
+                            and r.eng.kv.can_admit(total,
+                                                   planned.get(r.rix, 0))):
+                        target = r
+                        break
                 if target is None:
-                    break
+                    break                    # head-of-line: shed = wait
                 self.queue.pop(0)
                 target.eng.submit(req)
+                taken[target.rix] = taken.get(target.rix, 0) + 1
+                planned[target.rix] = (planned.get(target.rix, 0)
+                                       + target.eng.kv.pages_needed(total))
                 placed += 1
-                continue
-            total = len(req.prompt) + req.max_new_tokens - 1
-            target = None
-            for r in sorted(self.pool.serving,
-                            key=lambda r: (-(r.free_slots
-                                             - taken.get(r.rix, 0)), r.rix)):
-                if r.draining or not r.healthy:
-                    continue
-                if (r.free_slots - taken.get(r.rix, 0) > 0
-                        and r.eng.kv.can_admit(total,
-                                               planned.get(r.rix, 0))):
-                    target = r
-                    break
-            if target is None:
-                break                      # head-of-line: shed = wait
-            self.queue.pop(0)
-            target.eng.submit(req)
-            taken[target.rix] = taken.get(target.rix, 0) + 1
-            planned[target.rix] = (planned.get(target.rix, 0)
-                                   + target.eng.kv.pages_needed(total))
-            placed += 1
-        return placed
+            return placed
 
 
 class FleetExecutor:

@@ -250,6 +250,11 @@ class PagedKVCache:
     def n_free(self) -> int:
         return len(self._free)
 
+    @property
+    def n_reserved(self) -> int:
+        """Pages reserved for the slots' worst cases, held or not yet."""
+        return int(self.worst.sum())
+
     def pages_needed(self, n_tokens: int) -> int:
         return max(math.ceil(n_tokens / self.page_size), 1)
 
