@@ -1,7 +1,8 @@
 """Page-size / block-k autotuning for the decode-attention tier.
 
-The paged decode kernel's only tile knob is the page size (one grid step
-streams one page), and the dense flash-decoding kernel's is ``block_k``.
+The paged kernels' only tile knob here is the page size (pages per key
+block follow from the shapes, in the kernel), and the dense flash-decoding
+kernel's is ``block_k``.
 Neither has a universally best value: bigger pages amortize DMA issue and
 grid overhead but waste bandwidth on partially filled last pages and shrink
 the scheduler's allocation granularity; bigger ``block_k`` does the same for

@@ -65,7 +65,14 @@ def decode_attention_mixed(q, k_pages, v_pages, block_table, starts, *,
     ``starts == pos``, a prefill chunk has T == chunk_size, a speculative
     verify block T == 1 + draft_len); pages / block_table / scales as in
     :func:`decode_attention_paged`.  The span's own KV must be written
-    before the call.  Returns (B, T, Hq, D).
+    before the call.
+
+    The kernel visits only each row's live pages, in blocks of several
+    pages copied through the block table, and scores each KV head's
+    ``T * Hq / Hkv`` queries against that head's keys alone; pages per
+    block follow from the shapes (see
+    :func:`repro.kernels.decode_attention.kernel.paged_mixed_attention_fwd`).
+    Returns (B, T, Hq, D).
     """
     win = jnp.reshape(jnp.asarray(-1 if window is None else window, jnp.int32),
                       (1,))

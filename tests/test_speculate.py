@@ -101,26 +101,71 @@ def test_fused_lmhead_verify_shape():
 # mixed-span paged attention
 # ---------------------------------------------------------------------------------
 
-def test_mixed_kernel_matches_gather_sdpa():
-    """The T>1 block-table kernel == gather + span-masked SDPA, with and
-    without a sliding window, at heterogeneous span starts."""
+# id: (T, Hq, Hkv, D, page, table pages, starts, window, pool dtype).  A
+# start of -1 is a dead row: start 0 on the all-trash table.  At T 32 and
+# page 32 a block is 16 pages (512 keys) at group 4 and 8 pages (256 keys)
+# at group 8, so the 20- and 10-page tables hold two blocks.
+MIXED_CASES = {
+    "g2_t4": (4, 4, 2, 8, 4, 6, (0, 5, 13), None, "float32"),
+    "g2_t4_window": (4, 4, 2, 8, 4, 6, (0, 5, 13), 3, "float32"),
+    "g1_t1": (1, 4, 4, 8, 4, 6, (3, 17, -1), None, "float32"),
+    "g3_t4": (4, 9, 3, 16, 8, 6, (0, 9, 30), None, "float32"),
+    # 100: live keys end mid-block; 540: two blocks; a dead row
+    "g4_t32_blocks": (32, 8, 2, 8, 32, 20, (100, 540, -1), None, "float32"),
+    # 250: ends mid-way into the second block
+    "g8_t32_blocks": (32, 16, 2, 8, 32, 10, (200, 250, -1), None, "float32"),
+    # the window's first key (561 + 1 - 100) sits mid-page, mid-block
+    "g4_window_mid_block": (4, 8, 2, 8, 32, 20, (561, 30, 300), 100,
+                            "float32"),
+    "int8_window": (4, 4, 2, 8, 4, 6, (0, 5, 13), 6, "int8"),
+    # 128-lane bf16 heads: the kernel reads packed words
+    "bf16_g4_t32_blocks": (32, 8, 2, 128, 32, 20, (100, 540, -1), None,
+                           "bfloat16"),
+    "bf16_g3_odd_kv_heads": (4, 9, 3, 128, 16, 8, (20, 100, -1), None,
+                             "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("case", list(MIXED_CASES))
+def test_mixed_kernel_matches_gather_sdpa(case):
+    """The T>1 block-table kernel == gather + span-masked SDPA, over group
+    sizes 1-8, T of 1, 4 and 32, rows that end mid-block or span two
+    blocks, dead rows, windows, int8 pages and packed bf16 pages."""
     from repro.kernels.decode_attention.ops import decode_attention_mixed
     from repro.models.attention import sdpa
     from repro.serving.kvcache import paged_gather
-    B, T, Hq, Hkv, D, ps, n = 3, 4, 4, 2, 8, 4, 6
-    ks = jax.random.split(jax.random.key(9), 3)
-    kp = jax.random.normal(ks[0], (B * n + 1, ps, Hkv, D))
-    vp = jax.random.normal(ks[1], (B * n + 1, ps, Hkv, D))
-    q = jax.random.normal(ks[2], (B, T, Hq, D))
+    T, Hq, Hkv, D, ps, n, starts, win, dtype = MIXED_CASES[case]
+    B = len(starts)
+    ks = jax.random.split(jax.random.key(9), 5)
+    shape = (B * n + 1, ps, Hkv, D)
+    scales = {}
+    if dtype == "int8":
+        kp = jax.random.randint(ks[0], shape, -127, 128, jnp.int8)
+        vp = jax.random.randint(ks[1], shape, -127, 128, jnp.int8)
+        scales = {name: jax.random.uniform(k, shape[:3] + (1,), minval=5e-3,
+                                           maxval=3e-2)
+                  for name, k in (("k_scale", ks[3]), ("v_scale", ks[4]))}
+        q = jax.random.normal(ks[2], (B, T, Hq, D))
+    else:
+        kp = jax.random.normal(ks[0], shape, dtype)
+        vp = jax.random.normal(ks[1], shape, dtype)
+        q = jax.random.normal(ks[2], (B, T, Hq, D), dtype)
     tbl = jnp.arange(1, B * n + 1, dtype=jnp.int32).reshape(B, n)
-    starts = jnp.array([0, 5, 13], jnp.int32)
-    kd, vd = paged_gather(kp, tbl), paged_gather(vp, tbl)
-    for win in (None, 3):
-        out_k = decode_attention_mixed(q, kp, vp, tbl, starts, window=win)
-        mask = _span_mask(n * ps, starts, T, jnp.int32(-1 if win is None else win))
-        out_r = sdpa(q, kd, vd, mask)
-        np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
-                                   atol=1e-5)
+    dead = jnp.array([s < 0 for s in starts])[:, None]
+    tbl = jnp.where(dead, TRASH_PAGE, tbl)
+    starts = jnp.array([max(s, 0) for s in starts], jnp.int32)
+    kf, vf = kp.astype(jnp.float32), vp.astype(jnp.float32)
+    if scales:
+        kf, vf = kf * scales["k_scale"], vf * scales["v_scale"]
+    out_k = decode_attention_mixed(q, kp, vp, tbl, starts, window=win,
+                                   **scales)
+    mask = _span_mask(n * ps, starts, T, jnp.int32(-1 if win is None else win))
+    out_r = sdpa(q.astype(jnp.float32), paged_gather(kf, tbl),
+                 paged_gather(vf, tbl), mask)
+    # bf16: the reference scores the same bf16 values in f32 throughout
+    atol = 2e-2 if dtype == "bfloat16" else 1e-5
+    np.testing.assert_allclose(np.asarray(out_k, np.float32),
+                               np.asarray(out_r), atol=atol)
 
 
 def test_mixed_kernel_t1_equals_decode_kernel():

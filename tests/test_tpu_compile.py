@@ -74,6 +74,32 @@ def test_paged_mixed_attention_compiles(arch, one_chip):
     assert "tpu_custom_call" in txt
 
 
+# The benchmark cells' served shapes: (arch giving the widths, rows, table
+# pages); both at span 32 and page 32.  Mistral-NeMo's attention widths
+# (Hq 32, Hkv 8, D 128) are pixtral-12b's.
+CELL_SHAPES = {"qwen2.5-3b.chat": ("qwen2.5-3b", 32, 64),
+               "mistral-nemo-12b.l10.rag": ("pixtral-12b", 8, 128)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_paged_mixed_attention_compiles_at_cell_shapes(cell, one_chip):
+    """The mixed kernel compiles at each benchmark cell's served shapes, and
+    the compiled call still names ``_paged_mixed_kernel``: the benchmark
+    finds the kernel's operation by that name."""
+    from bench.trace import kernel_ops
+    from repro.kernels.decode_attention.kernel import paged_mixed_attention_fwd
+    arch, rows, pages = CELL_SHAPES[cell]
+    Hq, Hkv, D, _, _ = _widths(arch)
+    pool = (rows * pages + 1, PAGE, Hkv, D)
+    txt = _compiled_text(paged_mixed_attention_fwd, one_chip,
+                         ((rows, SPAN, Hq, D), jnp.bfloat16),
+                         (pool, jnp.bfloat16), (pool, jnp.bfloat16),
+                         ((rows, pages), jnp.int32), ((rows,), jnp.int32),
+                         ((1,), jnp.int32))
+    found = kernel_ops(txt, {"paged_mixed_attention": "_paged_mixed_kernel"})
+    assert list(found.values()) == ["paged_mixed_attention"]
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_paged_decode_attention_compiles(arch, one_chip):
     from repro.kernels.decode_attention.kernel import paged_decode_attention_fwd
