@@ -1,8 +1,9 @@
 """Roofline share of the fused lm-head epilogue (``lmhead_epilogue_fwd``):
 one call per loop iteration over max_batch x span rows; the least time of
-each call (the (rows, d) x (d, V) product, the weight read once) over the
-kernel's device time in the trace."""
-from bench.costs import Shapes, lmhead_call, roofline_seconds
+each call (the architecture's ``lmhead_call``: the (rows, d) x (d, V)
+product, the weight read once) over the kernel's device time in the
+trace."""
+from bench.costs import roofline_seconds
 from bench.peaks import peaks
 
 KERNEL = "lmhead_epilogue"
@@ -14,7 +15,7 @@ def read(run):
     iters = sum(s.iters for s in run.steps)
     if not t or not iters:
         return None
-    s = Shapes.from_config(run.spec["config"])
-    least = iters * roofline_seconds(*lmhead_call(s, run.max_batch * run.span),
-                                     peaks(run.device_kind))
+    least = iters * roofline_seconds(
+        *run.costs.lmhead_call(run.max_batch * run.span),
+        peaks(run.device_kind))
     return 100.0 * least / t

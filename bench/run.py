@@ -6,7 +6,11 @@ A cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); ``bench/cells/<cell>.json`` holds the
 numbers fixed for that cell on the chip (rate, limits, ramp, drain cap and
-the correctness limits).  Each metric is read by ``bench/metrics/<name>.py``.
+the correctness limits).  The configuration's ``bench_model`` key names its
+architecture, ``bench/models/<bench_model>.py``: the served model's
+configuration, the reference, the costs and any kernels of its own (the
+interface is in ``bench/models/dense.py``).  Each metric is read by
+``bench/metrics/<name>.py``.
 
 Set-up (``setup_s``, from process start): the compile cache, the model,
 its weights made on the chip from ``--seed`` in one jitted call, one
@@ -23,8 +27,9 @@ window and prints the per-layer metrics and a breakdown instead of the
 end-to-end ones.
 
 Afterwards, with the program's state freed, a sample of the finished
-requests is checked against ``bench/reference.py``; ``correct`` holds when
-every compared number is within its limit and every KV page is back.
+requests is checked against the architecture's reference; ``correct``
+holds when every compared number is within its limit and every KV page is
+back.
 
 The run exits non-zero, printing no result, where JAX finds no TPU or
 fewer chips than the cell asks for.
@@ -49,6 +54,7 @@ import numpy as np  # noqa: E402
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 OUT = ROOT / ".bench_out"          # profiler output (ignored by git)
+MODELS = BENCH / "models"          # one file per architecture
 # the benchmark's modules are imported as ``bench.<name>``: run as a script,
 # the checkout root takes this directory's place at the head of the path
 if sys.path and Path(sys.path[0] or ".").resolve() == BENCH:
@@ -59,6 +65,7 @@ for _p in (str(ROOT), str(ROOT / "src")):
 
 TRACE_AT = 0.5          # the profile starts at this share of the window
 TRACE_SECONDS = 4.0     # and lasts this long (at most a quarter of it)
+# the kernels every model runs; an architecture's ``KERNELS`` adds its own
 KERNELS = {"paged_mixed_attention": "_paged_mixed_kernel",
            "lmhead_epilogue": "_lmhead_epilogue_kernel"}
 
@@ -100,25 +107,38 @@ def device_info() -> dict:
             "count": len(devs)}
 
 
-def build_model(cfg: dict):
-    """The served model, built from the config file's numbers."""
-    import jax.numpy as jnp
-    from repro.models import build_model as build
-    from repro.models.common import ModelConfig
+def bench_model(cfg: dict, models: Path | None = None):
+    """The architecture module a configuration names by its ``bench_model``
+    key: ``<models>/<bench_model>.py``, ``bench/models/`` by default."""
+    models = Path(models or MODELS)
+    known = sorted(p.stem for p in models.glob("*.py"))
+    name = cfg.get("bench_model")
+    if name not in known:
+        raise SystemExit(f"configuration {cfg.get('source', '?')!r}: "
+                         f"bench_model {name!r} is not a module in "
+                         f"{models}; known: {known}")
+    path = (models / f"{name}.py").resolve()
+    key = f"bench_model_{name}"
+    mod = sys.modules.get(key)
+    if mod is None or Path(mod.__file__) != path:
+        mod_spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        sys.modules[key] = mod
+        mod_spec.loader.exec_module(mod)
+    return mod
 
-    d, hq = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
-    mc = ModelConfig(
-        name=Path(cfg.get("source", "model")).name, family="dense",
-        n_layers=int(cfg["num_hidden_layers"]), d_model=d, n_heads=hq,
-        n_kv_heads=int(cfg["num_key_value_heads"]),
-        d_ff=int(cfg["intermediate_size"]), vocab=int(cfg["vocab_size"]),
-        head_dim=int(cfg.get("head_dim") or d // hq),
-        rope_theta=float(cfg["rope_theta"]),
-        qkv_bias=bool(cfg["architecture"]["qkv_bias"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        dtype=jnp.bfloat16, remat="none")
-    return build(mc)
+
+def build_model(cfg: dict):
+    """The served model, built from the config file's numbers by its
+    architecture module."""
+    from repro.models import build_model as build
+    return build(bench_model(cfg).model_config(cfg))
+
+
+def kernel_names(arch) -> dict:
+    """{kernel name: Pallas function} of the kernels a model of ``arch``
+    runs: the shared ones and its own."""
+    return {**KERNELS, **arch.KERNELS}
 
 
 # -- client-side records ----------------------------------------------------------
@@ -263,8 +283,7 @@ class LoadLoop:
         self.longest_step = max(self.longest_step, (t1 - ts, ts))
         held = eng.kv.num_pages - 1 - eng.kv.n_free
         self.pages_peak = max(self.pages_peak, held)
-        self.reserved_peak = max(self.reserved_peak,
-                                 held + eng.kv._outstanding)
+        self.reserved_peak = max(self.reserved_peak, eng.kv.n_reserved)
         served = list(eng.active.items())
         fresh = eng.completed[self._seen_done:]
         self._seen_done = len(eng.completed)
@@ -301,8 +320,7 @@ class LoadLoop:
             self.steps.append(StepRec(eng.step_count - it0, rows))
 
     def _counters(self) -> dict:
-        st = self.eng.speculation_stats
-        return {"emitted": st["emitted"], "live_iters": st["live_iters"]}
+        return dict(self.eng.speculation_stats)
 
     def _stop_profile(self, span) -> None:
         import jax
@@ -406,16 +424,16 @@ def sample_for_check(loop, seed: int) -> list:
 
 def check(spec, loop, seed: int, control: bool) -> dict:
     """Compare the sampled requests with the reference: the compared numbers
-    with their limits.  With ``control`` the float8 control takes the
-    program's place in them (the program's own readings go on a line of
-    their own), so a control run has to come out not correct."""
-    from bench import reference
+    with their limits.  With ``control`` the reference's control (float8
+    for a bfloat16 model) takes the program's place in them (the program's
+    own readings go on a line of their own), so a control run has to come
+    out not correct."""
     picked = sample_for_check(loop, seed)
     seqs = [(np.asarray(loop.reqs[r.rid].prompt),
              np.asarray(loop.reqs[r.rid].output)) for r in picked]
     t0 = time.perf_counter()
-    res = reference.check_sequences(spec["config"], seed, seqs,
-                                    control=control)
+    res = bench_model(spec["config"]).check_sequences(
+        spec["config"], seed, seqs, control=control)
     gaps = [x["max_gap"] for x in res]
     score = [abs(loop.reqs[r.rid].score - x["mean_lp"])
              for r, x in zip(picked, res)]
@@ -462,6 +480,7 @@ class RunView:
     span: int
     device_kind: str
     profile: tuple
+    costs: object = None       # the architecture's ``costs(cfg)``
 
 
 def breakdown(summary, kernel_ops: dict) -> dict:
@@ -484,7 +503,6 @@ def setup_engine(spec: dict, seed: int, cache: bool = True):
     as ``ReplicaPool.spawn`` warms a replica)."""
     import jax
 
-    from bench import reference
     from repro.serving import Request, ServeConfig, ServingEngine
     from repro.serving.fleet import FleetRouter, Replica, ReplicaPool
     from repro.utils.compile_cache import enable_compile_cache
@@ -495,7 +513,8 @@ def setup_engine(spec: dict, seed: int, cache: bool = True):
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     Compiles.install()
     model = build_model(spec["config"])
-    params = jax.jit(model.init_params)(reference.params_key(seed))
+    params = jax.jit(model.init_params)(
+        bench_model(spec["config"]).params_key(seed))
     jax.block_until_ready(params)
     serve = spec["config"]["serve"]
     serve_cfg = ServeConfig(max_batch=int(serve["max_batch"]),
@@ -529,6 +548,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
               f"{info['count']}", file=sys.stderr)
         return 2
 
+    arch = bench_model(spec["config"])
     model, eng, rep, pool, router = setup_engine(spec, seed, cache)
     loop = LoadLoop(spec, router, rep, seed, seconds, trace)
     setup_s = time.perf_counter() - t_process
@@ -570,7 +590,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     if trace:
         from bench.trace import find_xplane, kernel_ops as find_kernels, load, summarize
         summary = summarize(load(find_xplane(str(OUT / "trace"))))
-        kernel_ops = find_kernels(loop.mixed_hlo(), KERNELS)
+        kernel_ops = find_kernels(loop.mixed_hlo(), kernel_names(arch))
         log(f"trace: window {summary.window_s:.6f}s, busy {summary.busy_s:.6f}s, "
             f"kernels {kernel_ops}")
     max_batch, span = eng.cfg.max_batch, eng.span
@@ -591,7 +611,8 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
                    recs=list(loop.recs.values()), steps=loop.steps,
                    counters=loop.counters, trace=summary,
                    kernel_ops=kernel_ops, max_batch=max_batch, span=span,
-                   device_kind=info["kind"], profile=loop.profile)
+                   device_kind=info["kind"], profile=loop.profile,
+                   costs=arch.costs(spec["config"]))
     metrics = {}
     for m in spec["per_layer" if trace else "end_to_end"]:
         value = reader(m["name"])(view)

@@ -17,7 +17,8 @@ import json
 
 from bench import run
 
-SMALL = {"hidden_size": 128, "intermediate_size": 256,
+SMALL = {"bench_model": "dense",
+         "hidden_size": 128, "intermediate_size": 256,
          "num_attention_heads": 4, "num_key_value_heads": 2,
          "num_hidden_layers": 2, "vocab_size": 1024, "rope_theta": 1e6,
          "rms_norm_eps": 1e-6, "tie_word_embeddings": True,

@@ -9,13 +9,15 @@ from bench import reference
 from bench.run import build_model
 
 SMALL = {
-    "tied_bias": {"hidden_size": 64, "intermediate_size": 128,
+    "tied_bias": {"bench_model": "dense",
+                  "hidden_size": 64, "intermediate_size": 128,
                   "num_attention_heads": 4, "num_key_value_heads": 2,
                   "num_hidden_layers": 2, "vocab_size": 256,
                   "rope_theta": 1e6, "rms_norm_eps": 1e-6,
                   "tie_word_embeddings": True,
                   "architecture": {"qkv_bias": True}},
-    "untied_wide_heads": {"hidden_size": 64, "intermediate_size": 96,
+    "untied_wide_heads": {"bench_model": "dense",
+                          "hidden_size": 64, "intermediate_size": 96,
                           "num_attention_heads": 8, "num_key_value_heads": 2,
                           "head_dim": 16, "num_hidden_layers": 3,
                           "vocab_size": 512, "rope_theta": 1e6,
