@@ -234,7 +234,8 @@ def sweep_span_width(widths=(1, 2, 4, 8, 16, 32), *, total_tokens: int = 256,
             from repro.kernels.decode_attention.ops import decode_attention_mixed
 
             def step(q, kp, vp, tbl, st):
-                return decode_attention_mixed(q, kp, vp, tbl, st)
+                return decode_attention_mixed(q, kp[None], vp[None], tbl, st,
+                                              0)
         us = _time_jitted(jax.jit(step), q, k_pages, v_pages, tbl, starts,
                           reps=reps)
         rows.append({"span_width": int(T), "us_per_step": us,

@@ -118,6 +118,15 @@ def _lanes(width: int) -> int:
     return pl.cdiv(width, _LANES) * _LANES
 
 
+def pads_pools(*pools) -> bool:
+    """True if :func:`paged_mixed_attention_fwd` pads one of ``pools`` to
+    whole 128-lane rows on every call: a head dim that is not a multiple of
+    128, or int8's ``(..., 1)`` scales.  Otherwise its row view of a pool
+    is a reshape that copies nothing."""
+    return any(p is not None and _lanes(p.shape[-1]) != p.shape[-1]
+               for p in pools)
+
+
 def _packing(dtype) -> int:
     """Elements of ``dtype`` in one 32-bit word."""
     return 4 // jnp.dtype(dtype).itemsize

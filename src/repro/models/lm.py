@@ -245,9 +245,8 @@ def block_decode(x, bp, window, cache_k, cache_v, pos, cos, sin, cfg: ModelConfi
     return x + f, (cache_k, cache_v, cache_ks, cache_vs)
 
 
-def block_verify(x, bp, window, cache_k, cache_v, pos, cos, sin, cfg: ModelConfig,
-                 cache_ks=None, cache_vs=None, block_table=None,
-                 use_kernel: bool = False):
+def block_verify(x, bp, window, layer, pools, pos, cos, sin, cfg: ModelConfig,
+                 block_table, use_kernel: bool = False):
     """Span decode: x (B, T, d), each row's T tokens at consecutive logical
     positions starting at ``pos[b]``.
 
@@ -255,41 +254,53 @@ def block_verify(x, bp, window, cache_k, cache_v, pos, cos, sin, cfg: ModelConfi
     KV is scattered into the paged pool first (so query t attends its own
     key), then per-query causal attention runs over the row's pages -- via
     the mixed Pallas kernel or the gather + span-mask route.  T = 1 is
-    exactly :func:`block_decode`."""
-    int8_kv = cache_ks is not None
+    exactly :func:`block_decode`.
+
+    ``pools``: every layer's pools, {"k", "v"[, "k_scale", "v_scale"]} of
+    (L, P, page_size, ...); the block writes and reads layer ``layer``
+    through the (L * P, ...) page view, where layer l's page p is page
+    l * P + p, so the pools are updated in place and no layer is copied
+    out.  Returns (x, pools)."""
     h = rms_norm(x, bp["ln1"], cfg.norm_eps)
     q, k, v = _project_qkv(h, bp, cfg)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
+    int8_kv = cfg.kv_cache_dtype == "int8"
     if int8_kv:
-        k_store, k_sc = _kv_quantize(k)
-        v_store, v_sc = _kv_quantize(v)
+        (kq, ks), (vq, vs) = _kv_quantize(k), _kv_quantize(v)
+        new = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
     else:
-        k_store, v_store = k, v
-    ops = kvcache.PagedOps(block_table)
-    cache_k = ops.write_span(cache_k, k_store, pos)
-    cache_v = ops.write_span(cache_v, v_store, pos)
-    if int8_kv:
-        cache_ks = ops.write_span(cache_ks, k_sc, pos)
-        cache_vs = ops.write_span(cache_vs, v_sc, pos)
+        new = {"k": k, "v": v}
+    L, P = pools["k"].shape[:2]
+    ops = kvcache.PagedOps(block_table + layer * P)
+
+    def pages(pool):
+        return pool.reshape((L * P,) + pool.shape[2:])
+
+    def write(pool, span):
+        return ops.write_span(pages(pool), span, pos).reshape(pool.shape)
+
+    pools = jax.tree.map(write, pools, new)
     if use_kernel:
         from repro.kernels.decode_attention.ops import decode_attention_mixed
-        o = decode_attention_mixed(q, cache_k, cache_v, block_table, pos,
-                                   window=window,
-                                   k_scale=cache_ks if int8_kv else None,
-                                   v_scale=cache_vs if int8_kv else None)
+        o = decode_attention_mixed(q, pools["k"], pools["v"], block_table, pos,
+                                   layer, window=window,
+                                   k_scale=pools.get("k_scale"),
+                                   v_scale=pools.get("v_scale"))
     else:
-        k_eff = ops.view(cache_k)
-        v_eff = ops.view(cache_v)
+        k_eff = ops.view(pages(pools["k"]))
+        v_eff = ops.view(pages(pools["v"]))
         if int8_kv:
-            k_eff = _kv_dequantize(k_eff, ops.view(cache_ks), cfg.dtype)
-            v_eff = _kv_dequantize(v_eff, ops.view(cache_vs), cfg.dtype)
+            k_eff = _kv_dequantize(k_eff, ops.view(pages(pools["k_scale"])),
+                                   cfg.dtype)
+            v_eff = _kv_dequantize(v_eff, ops.view(pages(pools["v_scale"])),
+                                   cfg.dtype)
         mask = ops.span_mask(k_eff.shape[1], pos, q.shape[1], window)
         o = sdpa(q, k_eff, v_eff, mask)
     x = x + o.reshape(*x.shape[:2], -1) @ bp["wo"]
     h = rms_norm(x, bp["ln2"], cfg.norm_eps)
     f, _ = _ffn(h, bp, cfg)
-    return x + f, (cache_k, cache_v, cache_ks, cache_vs)
+    return x + f, pools
 
 
 # ---------------------------------------------------------------------------------
@@ -488,29 +499,20 @@ def verify_step(params, cache, tokens, pos, cfg: ModelConfig, *,
     cos, sin = rope_tables(pos[:, None] + jnp.arange(T)[None, :],
                            cfg.resolved_head_dim, cfg.rope_theta)
     windows = layer_windows(cfg)
-    int8_kv = cfg.kv_cache_dtype == "int8"
 
-    def body(x, layer):
-        if int8_kv:
-            bp, w, ck, cv, cks, cvs = layer
-        else:
-            bp, w, ck, cv = layer
-            cks = cvs = None
-        x, (ck, cv, cks, cvs) = block_verify(x, bp, w, ck, cv, pos, cos, sin,
-                                             cfg, cache_ks=cks, cache_vs=cvs,
-                                             block_table=block_table,
-                                             use_kernel=use_kernel)
-        return x, ((ck, cv, cks, cvs) if int8_kv else (ck, cv))
+    # the pools ride in the scan's carry, so each layer writes its span
+    # into them in place; as xs and ys, every layer's pool would be copied
+    # out and stacked back in every iteration
+    def body(carry, layer):
+        x, pools = carry
+        bp, w, idx = layer
+        return block_verify(x, bp, w, idx, pools, pos, cos, sin, cfg,
+                            block_table=block_table,
+                            use_kernel=use_kernel), None
 
-    if int8_kv:
-        x, (ks, vs, kss, vss) = jax.lax.scan(
-            body, x, (params["blocks"], windows, cache["k"], cache["v"],
-                      cache["k_scale"], cache["v_scale"]))
-        new_cache = {"k": ks, "v": vs, "k_scale": kss, "v_scale": vss}
-    else:
-        x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], windows,
-                                             cache["k"], cache["v"]))
-        new_cache = {"k": ks, "v": vs}
+    (x, new_cache), _ = jax.lax.scan(
+        body, (x, cache),
+        (params["blocks"], windows, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = rms_norm(x, params["ln_f"], cfg.norm_eps)
     w_head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     tok, lp = fused_lmhead_greedy(x, w_head, use_kernel=lmhead_kernel,

@@ -177,7 +177,9 @@ class ServingEngine:
             self.proposer = (make_proposer(cfg.proposer, self.span - 1,
                                            ngram=cfg.ngram)
                              if self.span > 1 else None)
-            self._mixed_jit = jax.jit(self._mixed_step_fn)
+            # the pool is donated: the loop writes it in place, and every
+            # caller replaces ``self.kv.pages`` with the pool returned
+            self._mixed_jit = jax.jit(self._mixed_step_fn, donate_argnums=(1,))
         else:
             self.spec_len = 0
             self.span = 1

@@ -130,7 +130,9 @@ MIXED_CASES = {
 def test_mixed_kernel_matches_gather_sdpa(case):
     """The T>1 block-table kernel == gather + span-masked SDPA, over group
     sizes 1-8, T of 1, 4 and 32, rows that end mid-block or span two
-    blocks, dead rows, windows, int8 pages and packed bf16 pages."""
+    blocks, dead rows, windows, int8 pages and packed bf16 pages.  The
+    pools are stacked two layers deep and the kernel reads layer 1, so a
+    read of the wrong layer (layer 0 holds other values) shows."""
     from repro.kernels.decode_attention.ops import decode_attention_mixed
     from repro.models.attention import sdpa
     from repro.serving.kvcache import paged_gather
@@ -157,8 +159,11 @@ def test_mixed_kernel_matches_gather_sdpa(case):
     kf, vf = kp.astype(jnp.float32), vp.astype(jnp.float32)
     if scales:
         kf, vf = kf * scales["k_scale"], vf * scales["v_scale"]
-    out_k = decode_attention_mixed(q, kp, vp, tbl, starts, window=win,
-                                   **scales)
+    def stacked(x):
+        return jnp.stack([x[::-1], x])             # layer 0: other values
+    out_k = decode_attention_mixed(
+        q, stacked(kp), stacked(vp), tbl, starts, 1, window=win,
+        **{name: stacked(x) for name, x in scales.items()})
     mask = _span_mask(n * ps, starts, T, jnp.int32(-1 if win is None else win))
     out_r = sdpa(q.astype(jnp.float32), paged_gather(kf, tbl),
                  paged_gather(vf, tbl), mask)
@@ -179,7 +184,7 @@ def test_mixed_kernel_t1_equals_decode_kernel():
     q = jax.random.normal(ks[2], (B, 1, Hq, D))
     tbl = jnp.arange(1, B * n + 1, dtype=jnp.int32).reshape(B, n)
     pos = jnp.array([3, 11], jnp.int32)
-    out_m = decode_attention_mixed(q, kp, vp, tbl, pos)
+    out_m = decode_attention_mixed(q, kp[None], vp[None], tbl, pos, 0)
     out_d = decode_attention_paged(q[:, 0][:, None], kp, vp, tbl, pos + 1)
     np.testing.assert_allclose(np.asarray(out_m), np.asarray(out_d), atol=1e-5)
 

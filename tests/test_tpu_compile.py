@@ -8,7 +8,12 @@ Each test asserts that the compiled program holds the kernel
 (``tpu_custom_call``).  The topology is described inside a fixture, never
 at import: only one process may load the TPU library at a time.
 """
+import dataclasses
+import json
+import math
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +22,7 @@ import pytest
 from repro.configs import get_config
 
 ARCHS = ("qwen2.5-3b", "smollm-135m")
+BENCH_CONFIGS = Path(__file__).resolve().parents[1] / "bench" / "configs"
 BATCH, SPAN, PAGE, PAGES_PER_ROW = 8, 32, 32, 32     # max_len 1024
 
 
@@ -153,3 +159,115 @@ def test_ssd_intra_chunk_compiles(one_chip):
                          ((4, q, heads, s.d_state), jnp.float32),
                          ((4, q, heads, s.d_state), jnp.float32))
     assert "tpu_custom_call" in txt
+
+
+# -- the whole mixed step ---------------------------------------------------
+
+# Each benchmark cell's configuration file, and the layers compiled of it
+# (its widths and ``serve`` sizes are the file's).
+STEP_CELLS = {"qwen2.5-3b.chat": ("qwen2.5-3b", 4),
+              "mistral-nemo-12b.l10.rag": ("mistral-nemo-12b.l10", 3)}
+_SHAPE = re.compile(r"\b[a-z]+\d*\[([\d,]*)\]")
+_OP = re.compile(r" = .*? ([a-z][\w-]*)\(")
+
+
+def _mixed_step(mc, serve, one_chip, monkeypatch):
+    """Compile ``ServingEngine._mixed_step_fn`` of model ``mc`` at ``serve``
+    sizes and the TPU's tuned defaults, from shapes alone, with the
+    kernels compiled for the chip (the CPU would interpret them).  Returns
+    the compiled text and the pool's leaves (L, P, page, Hkv, w)."""
+    from repro.kernels.decode_attention import ops as attention_ops
+    from repro.kernels.decode_attention.autotune import DEFAULTS
+    from repro.kernels.sampling import ops as sampling_ops
+    from repro.models import build_model
+    from repro.serving import ServeConfig, ServingEngine
+    for mod in (attention_ops, sampling_ops):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    tpu = DEFAULTS["tpu"]
+    model = build_model(mc, use_kernel=True)
+    cfg = ServeConfig(max_batch=serve["max_batch"], max_len=serve["max_len"],
+                      page_size=tpu["page_size"], chunk_size=tpu["chunk_size"],
+                      draft_len=tpu["draft_len"],
+                      lmhead_block_v=tpu["lmhead_block_v"], num_pages=2)
+    eng = ServingEngine(model, None, cfg)     # its own pool: 2 pages
+    na, H = cfg.max_batch, cfg.max_len + 1
+    n_table = cfg.max_len // cfg.page_size
+    P = na * n_table + 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = jax.tree.map(
+        lambda s: sds((s.shape[0], P) + s.shape[2:], s.dtype),
+        jax.eval_shape(lambda: model.init_cache(1, cfg.page_size)))
+    params = jax.tree.map(lambda s: sds(s.shape, s.dtype),
+                          model.abstract_params())
+    i32 = jnp.int32
+    args = (params, pool, sds((na, H), i32), sds((na,), i32),
+            sds((na,), i32), sds((na,), i32), sds((na,), jnp.bool_),
+            sds((na, n_table), i32), sds((), i32))
+    txt = eng._mixed_jit.lower(*args).compile().as_text()
+    return txt, [leaf.shape for leaf in jax.tree.leaves(pool)]
+
+
+def _ops(txt):
+    """(opcode, [element count of each result shape], line) of every
+    instruction, fused computations' included."""
+    for line in txt.splitlines():
+        m = _OP.search(line)
+        if m is None or "%" not in line.split(" = ", 1)[0]:
+            continue
+        head = line[:m.end()]
+        counts = [math.prod(int(d) for d in dims.split(",") if d)
+                  for dims in _SHAPE.findall(head.split(" = ", 1)[1])]
+        yield m.group(1), counts, line
+
+
+def _aliased_params(txt):
+    """Shapes of the entry parameters the compiled program writes in place
+    (its ``input_output_alias``)."""
+    header = txt.splitlines()[0]
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", header)
+    params = re.search(r"entry_computation_layout=\{\((.*?)\)->", header)
+    shapes = [tuple(int(d) for d in dims.split(",") if d)
+              for dims in _SHAPE.findall(params.group(1))]
+    return [shapes[int(i)] for i in
+            re.findall(r"\((\d+), \{\}", alias.group(1) if alias else "")]
+
+
+@pytest.mark.parametrize("cell", sorted(STEP_CELLS))
+def test_mixed_step_keeps_the_kv_pool_in_place(cell, one_chip, monkeypatch):
+    """The whole mixed step at a cell's widths and sizes: the loop writes
+    the KV pool in place.  No operation slices, stacks, copies or allocates
+    a buffer the size of a pool leaf or of one layer of it; each pool
+    parameter is donated (aliased to an output); and the compiled step
+    still names ``_paged_mixed_kernel``, which the benchmark reads."""
+    from bench.models import dense
+    from bench.trace import kernel_ops
+    name, n_layers = STEP_CELLS[cell]
+    spec = json.loads((BENCH_CONFIGS / f"{name}.json").read_text())
+    mc = dataclasses.replace(dense.model_config(spec), n_layers=n_layers)
+    txt, leaves = _mixed_step(mc, spec["serve"], one_chip, monkeypatch)
+    leaf = math.prod(leaves[0])
+    pool_sized = {leaf, leaf // n_layers}
+    moves = {"dynamic-slice", "dynamic-update-slice", "copy", "copy-start",
+             "copy-done"}
+    found = [line.strip()[:160] for op, counts, line in _ops(txt)
+             if pool_sized & set(counts)
+             and (op in moves or 'custom_call_target="AllocateBuffer"' in line)]
+    assert not found, "\n".join(found)
+    assert sorted(_aliased_params(txt)) == sorted(leaves)
+    assert "paged_mixed_attention" in kernel_ops(
+        txt, {"paged_mixed_attention": "_paged_mixed_kernel"}).values()
+
+
+def test_mixed_step_pads_one_layer_at_narrow_heads(one_chip, monkeypatch):
+    """At a head dim of 64 the kernel's row view pads each pool to 128
+    lanes: the step pads one layer per call, never the stacked pools."""
+    n_layers = 3
+    mc = dataclasses.replace(get_config("smollm-135m"), n_layers=n_layers)
+    txt, leaves = _mixed_step(mc, {"max_batch": 8, "max_len": 1024}, one_chip,
+                              monkeypatch)
+    layer = math.prod(leaves[0]) // n_layers
+    pads = [counts[0] for op, counts, _ in _ops(txt) if op == "pad"]
+    assert pads and max(pads) == 2 * layer      # 64 -> 128 lanes, one layer
